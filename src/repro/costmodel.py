@@ -192,12 +192,3 @@ def cost_of(fn, *args) -> CostStats:
     st.bytes_fused += io
     return st
 
-
-def xla_cost_analysis(compiled) -> dict:
-    """Normalize ``compiled.cost_analysis()`` across jax versions: older
-    releases return a one-element list of dicts, newer ones the dict
-    itself (or None when the backend provides nothing)."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost or {}

@@ -23,6 +23,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.platform import pallas_call
+
 NEG_INF = -1e30
 
 
@@ -68,8 +70,7 @@ def _body(q_ref, k_ref, v_ref, o_ref, m_sc, l_sc, acc_sc, *,
         o_ref[0, 0, 0] = (acc_sc[...] / l[:, None]).astype(o_ref.dtype)
 
 
-def flash_attention_kernel(q, k, v, *, causal=True, blk_q=128, blk_k=128,
-                           interpret=True):
+def flash_attention_kernel(q, k, v, *, causal=True, blk_q=128, blk_k=128):
     """q: (B, Sq, H, dh); k/v: (B, Sk, KV, dh/dv); GQA via H = KV * G."""
     b, sq, h, dh = q.shape
     sk, kvh = k.shape[1], k.shape[2]
@@ -86,7 +87,7 @@ def flash_attention_kernel(q, k, v, *, causal=True, blk_q=128, blk_k=128,
     kr = k.transpose(0, 2, 1, 3)          # (b, kv, sk, dh)
     vr = v.transpose(0, 2, 1, 3)
 
-    out = pl.pallas_call(
+    out = pallas_call(
         functools.partial(_body, scale=scale, causal=causal, blk_q=blk_q,
                           blk_k=blk_k, n_k=n_k),
         grid=(b, kvh, g, n_q, n_k),
@@ -106,6 +107,5 @@ def flash_attention_kernel(q, k, v, *, causal=True, blk_q=128, blk_k=128,
             pltpu.VMEM((blk_q,), jnp.float32),
             pltpu.VMEM((blk_q, dv), jnp.float32),
         ],
-        interpret=interpret,
     )(qr, kr, vr)
     return out.transpose(0, 3, 1, 2, 4).reshape(b, sq, h, dv)

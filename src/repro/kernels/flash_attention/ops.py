@@ -8,9 +8,8 @@ import jax
 from repro.kernels.flash_attention.kernel import flash_attention_kernel
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("causal", "blk_q", "blk_k", "interpret"))
+@functools.partial(jax.jit, static_argnames=("causal", "blk_q", "blk_k"))
 def flash_attention(q, k, v, causal: bool = True, blk_q: int = 128,
-                    blk_k: int = 128, interpret: bool = True):
+                    blk_k: int = 128):
     return flash_attention_kernel(q, k, v, causal=causal, blk_q=blk_q,
-                                  blk_k=blk_k, interpret=interpret)
+                                  blk_k=blk_k)

@@ -8,9 +8,10 @@ dependent (a = a*c1 + c2), so runtime tracks clock frequency rather than
 memory bandwidth — the property the methodology needs from its workload.
 
 On real hardware the per-iteration timestamps come from the host bracketing
-kernel launches (TPU exposes no in-kernel global timer — DESIGN.md #2); in
-this repo the simulator provides the timeline and this kernel is validated
-for numerical equivalence against ref.py in interpret mode.
+kernel launches (TPU exposes no in-kernel global timer); the simulator
+provides the timeline for the measurement pipeline.  The kernel is compiled
+by Mosaic on a TPU and interpreted on the CPU, where the tests check it
+against ref.py bit for bit.
 """
 from __future__ import annotations
 
@@ -19,6 +20,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels.platform import pallas_call
 
 TILE = (8, 128)          # float32 VPU tile
 
@@ -37,15 +40,14 @@ def _body(x_ref, o_ref, *, n_iters, unroll):
     o_ref[...] = a
 
 
-def microbench_kernel(x: jax.Array, *, n_iters: int = 64, unroll: int = 32,
-                      interpret: bool = True) -> jax.Array:
+def microbench_kernel(x: jax.Array, *, n_iters: int = 64,
+                      unroll: int = 32) -> jax.Array:
     """x: (cores * 8, 128) float32 — one (8,128) tile per core."""
     cores = x.shape[0] // TILE[0]
-    return pl.pallas_call(
+    return pallas_call(
         functools.partial(_body, n_iters=n_iters, unroll=unroll),
         grid=(cores,),
         in_specs=[pl.BlockSpec(TILE, lambda i: (i, 0))],
         out_specs=pl.BlockSpec(TILE, lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
-        interpret=interpret,
     )(x)

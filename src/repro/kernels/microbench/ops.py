@@ -9,11 +9,9 @@ import jax.numpy as jnp
 from repro.kernels.microbench.kernel import TILE, microbench_kernel
 
 
-@functools.partial(jax.jit, static_argnames=("n_iters", "unroll", "interpret"))
-def microbench(x: jax.Array, n_iters: int = 64, unroll: int = 32,
-               interpret: bool = True) -> jax.Array:
-    return microbench_kernel(x, n_iters=n_iters, unroll=unroll,
-                             interpret=interpret)
+@functools.partial(jax.jit, static_argnames=("n_iters", "unroll"))
+def microbench(x: jax.Array, n_iters: int = 64, unroll: int = 32) -> jax.Array:
+    return microbench_kernel(x, n_iters=n_iters, unroll=unroll)
 
 
 def make_input(cores: int, seed: int = 0) -> jax.Array:

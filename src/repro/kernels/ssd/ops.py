@@ -5,20 +5,16 @@ contract) for seq lengths divisible by the chunk size.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 
 from repro.kernels.ssd.kernel import ssd_chunk_kernel
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def ssd_chunk(x, B, C, cs, dt, interpret: bool = True):
-    return ssd_chunk_kernel(x, B, C, cs, dt, interpret=interpret)
+ssd_chunk = jax.jit(ssd_chunk_kernel)
 
 
-def ssd_pallas(x, dt, A, B, C, chunk: int, *, interpret: bool = True):
+def ssd_pallas(x, dt, A, B, C, chunk: int):
     """x: (b, l, h, p); dt: (b, l, h); A: (h,); B/C: (b, l, n).
     Returns (y (b,l,h,p) fp32, final state (b,h,p,n) fp32)."""
     b, l, h, p = x.shape
@@ -32,7 +28,7 @@ def ssd_pallas(x, dt, A, B, C, chunk: int, *, interpret: bool = True):
     dA = dtr * A[None, None, :, None]                    # (b,nc,h,q)
     cs = jnp.cumsum(dA, axis=-1)
 
-    y_intra, S = ssd_chunk(xr, Br, Cr, cs, dtr, interpret=interpret)
+    y_intra, S = ssd_chunk(xr, Br, Cr, cs, dtr)
 
     # inter-chunk recurrence (tiny sequential scan, stays in XLA)
     dA_chunk = jnp.exp(cs[..., -1])                      # (b,nc,h)

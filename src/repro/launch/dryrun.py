@@ -70,7 +70,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, *,
         return cell
 
     mem = compiled.memory_analysis()
-    xla_cost = costmodel.xla_cost_analysis(compiled)
+    xla_cost = compiled.cost_analysis()
     hlo = compiled.as_text()
     coll = parse_collective_bytes(hlo, mesh.size)
     mflops = roofline.model_flops(cfg, shape)

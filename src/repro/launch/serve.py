@@ -10,19 +10,21 @@ from repro.configs import ARCH_IDS, get_config
 from repro.configs.registry import model_module
 from repro.configs.shapes import ShapeSpec
 from repro.data.synthetic import make_batch
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_smoke_mesh
 from repro.parallel.sharding import make_env
 from repro.runtime.serve_loop import ServeConfig, serve
 
 
-def main():
+def main(argv=None) -> dict:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS, default="qwen3-32b")
     ap.add_argument("--smoke", action="store_true")
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--seq", type=int, default=32)
     ap.add_argument("--new-tokens", type=int, default=16)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     cfg = get_config(args.arch, smoke=args.smoke)
     env = make_env(cfg, make_smoke_mesh() if args.smoke else None)
@@ -35,6 +37,7 @@ def main():
     print(f"prefill {res['prefill_s']*1e3:.0f} ms, "
           f"decode {res['tokens_per_s']:.1f} tok/s, "
           f"first row: {res['tokens'][0][:8].tolist()}")
+    return res
 
 
 if __name__ == "__main__":

@@ -6,12 +6,14 @@ import argparse
 
 from repro.configs import ARCH_IDS, get_config
 from repro.configs.shapes import ShapeSpec
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_smoke_mesh
 from repro.parallel.sharding import make_env
 from repro.runtime.train_loop import TrainConfig, train
 
 
-def main():
+def main(argv=None) -> dict:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS, default="llama3-8b")
     ap.add_argument("--smoke", action="store_true",
@@ -24,7 +26,7 @@ def main():
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--governor", choices=("a100", "gh200", "rtx6000"),
                     default=None)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     cfg = get_config(args.arch, smoke=args.smoke)
     shape = ShapeSpec("cli", args.seq, args.batch, "train")
@@ -55,6 +57,7 @@ def main():
           f"mean step: {sum(m['step_time'])/len(m['step_time'])*1e3:.0f} ms")
     if m["governor"]:
         print("governor:", m["governor"])
+    return m
 
 
 if __name__ == "__main__":

@@ -472,8 +472,7 @@ def flash_decode_shardmap(q, k_cache, v_cache, k_new, v_new, pos, env):
     qs = P(dspec, None, None, None)
     cs = P(dspec, axis, None, None)
     ns = P(dspec, None, None, None)
-    from repro.parallel.sharding import shard_map
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(qs, cs, cs, ns, ns, P()),
         out_specs=(qs, cs, cs),

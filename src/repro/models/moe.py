@@ -134,8 +134,7 @@ def moe_apply(p, x, cfg, env):
                 capacity=cap, compute_dtype=cd)
             return jax.lax.psum(out, axis)
 
-        from repro.parallel.sharding import shard_map
-        out = shard_map(
+        out = jax.shard_map(
             body, mesh=env.mesh,
             in_specs=(P(*tok_spec, None), P(*tok_spec, None), P(*tok_spec, None),
                       P(axis, None, None), P(axis, None, None),

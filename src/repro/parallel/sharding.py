@@ -27,18 +27,6 @@ import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=False):
-    """jax.shard_map across jax versions: new releases expose it at the top
-    level with ``check_vma``; 0.4.x has jax.experimental.shard_map with the
-    same knob named ``check_rep``."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma)
-
-
 @dataclasses.dataclass(frozen=True)
 class MeshEnv:
     """Physical mesh + the logical->physical axis mapping for one model."""

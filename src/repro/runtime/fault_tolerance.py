@@ -150,15 +150,21 @@ class StragglerPolicy:
 
 
 def retry_step(fn, *args, retries: int = 3, on_error=None):
-    last = None
+    """Call ``fn(*args)`` up to ``retries`` times; raise the FIRST error
+    when every attempt fails.  A step jitted with donated inputs (the
+    training step) loses them on its first failed attempt, so the later
+    attempts fail only on deleted buffers, and their errors would hide the
+    cause."""
+    first = None
     for i in range(retries):
         try:
             return fn(*args)
         except Exception as e:      # noqa: BLE001 — deliberate catch-all boundary
-            last = e
+            if first is None:
+                first = e
             if on_error is not None:
                 on_error(i, e)
-    raise last
+    raise first
 
 
 def elastic_remesh(devices=None, *, axis_names=("data", "model")):

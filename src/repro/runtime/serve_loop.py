@@ -13,6 +13,7 @@ telemetry trace.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 
 import jax
@@ -28,21 +29,28 @@ class ServeConfig:
     seed: int = 0
 
 
+# jitted once per (cfg, env, max_len), so a second serve() call with the
+# same shapes runs compiled code and times serving, not compilation
+@functools.partial(jax.jit, static_argnums=(2, 3, 4))
+def _prefill(params, batch, cfg, env, max_len):
+    return decode_module(cfg).prefill(params, batch, cfg, env, max_len)
+
+
+@functools.partial(jax.jit, static_argnums=(4, 5), donate_argnums=(1,))
+def _decode_step(params, cache, tok, pos, cfg, env):
+    return decode_module(cfg).decode_step(params, cache, tok, pos, cfg, env)
+
+
 def serve(cfg, env, params, batch, sc: ServeConfig | None = None,
           max_len: int | None = None, verbose=False,
           governor=None, device=None) -> dict:
     if sc is None:
         sc = ServeConfig()
-    dec = decode_module(cfg)
     b, s = batch["tokens"].shape
     max_len = max_len or (s + sc.max_new_tokens)
 
-    prefill = jax.jit(lambda p, bt: dec.prefill(p, bt, cfg, env, max_len))
-    step = jax.jit(lambda p, c, t, i: dec.decode_step(p, c, t, i, cfg, env),
-                   donate_argnums=(1,))
-
     t0 = time.perf_counter()
-    logits, cache = prefill(params, batch)
+    logits, cache = _prefill(params, batch, cfg, env, max_len)
     jax.block_until_ready(logits)
     t_prefill = time.perf_counter() - t0
 
@@ -59,7 +67,8 @@ def serve(cfg, env, params, batch, sc: ServeConfig | None = None,
     out = [tok]
     t0 = time.perf_counter()
     for i in range(sc.max_new_tokens - 1):
-        logits, cache = step(params, cache, tok, jnp.int32(s + i))
+        logits, cache = _decode_step(params, cache, tok, jnp.int32(s + i),
+                                     cfg, env)
         tok = jnp.argmax(logits, -1)[:, None].astype(jnp.int32)
         out.append(tok)
     jax.block_until_ready(tok)
@@ -73,6 +82,7 @@ def serve(cfg, env, params, batch, sc: ServeConfig | None = None,
     tokens = jnp.concatenate(out, axis=1)
     return {
         "tokens": tokens,
+        "logits": logits,              # of the last generated token
         "prefill_s": t_prefill,
         "decode_s": t_decode,
         "tokens_per_s": (b * (sc.max_new_tokens - 1)) / max(t_decode, 1e-9),

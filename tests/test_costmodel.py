@@ -19,8 +19,8 @@ def test_xla_cost_analysis_is_loop_blind():
 
     x = jax.ShapeDtypeStruct((512, 512), jnp.float32)
     w = jax.ShapeDtypeStruct((512, 512), jnp.float32)
-    f1 = costmodel.xla_cost_analysis(jax.jit(one).lower(x, w).compile())["flops"]
-    f10 = costmodel.xla_cost_analysis(jax.jit(scan10).lower(x, w).compile())["flops"]
+    f1 = jax.jit(one).lower(x, w).compile().cost_analysis()["flops"]
+    f10 = jax.jit(scan10).lower(x, w).compile().cost_analysis()["flops"]
     # XLA may unroll tiny loops; at this size the loop survives and the body
     # is counted once (or at most a couple of times) instead of 10x
     assert f10 < 5 * f1                    # the undercount

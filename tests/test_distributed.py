@@ -1,14 +1,16 @@
 """Real multi-device SPMD correctness: runs a subprocess with 8 host
 devices (XLA_FLAGS) and checks that sharded execution is numerically
-equivalent to single-device execution for the core paths:
+equivalent to single-device execution for the core paths
+(:func:`repro.parallel.equivalence.check_sharded_equivalence` on a (2,4)
+("data","model") mesh — ``chip_smoke.py --chips 4`` runs the same checks
+on four chips):
 
-  * train step on a (2,4) ("data","model") mesh == unsharded step
+  * train step == unsharded step
   * flash-decoding (seq-sharded KV, shard_map LSE combine) == plain decode
   * shard_map expert-parallel MoE == local dispatch
 
-This is the strongest runnability evidence the container allows short of
-real hardware: the SAME code paths the 512-chip dry-run compiles are
-executed and checked for value equality.
+The SAME code paths the 512-chip dry-run compiles are executed and checked
+for value equality.
 """
 import os
 import subprocess
@@ -19,87 +21,10 @@ import pytest
 _SCRIPT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-import dataclasses
-import jax, jax.numpy as jnp, numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
+from repro.launch.mesh import make_mesh
+from repro.parallel.equivalence import check_sharded_equivalence
 
-from repro.configs import get_config
-from repro.configs.registry import model_module, decode_module
-from repro.launch.specs import abstract_init, make_train_step
-from repro.optim import adamw
-from repro.parallel.sharding import make_env, param_shardings
-
-mesh = jax.make_mesh((2, 4), ("data", "model"))
-
-def fp32(cfg):
-    return dataclasses.replace(cfg, param_dtype=jnp.float32,
-                               compute_dtype=jnp.float32)
-
-# ---------------- train step equivalence (llama3 smoke) ----------------- #
-cfg = fp32(get_config("llama3-8b", smoke=True))
-mod = model_module(cfg)
-params, axes = mod.init(jax.random.PRNGKey(0), cfg)
-opt = adamw.init(params)
-batch = {"tokens": jax.random.randint(jax.random.PRNGKey(1), (4, 32), 0,
-                                      cfg.vocab)}
-
-env1 = make_env(cfg, None)
-loss1, p1, _ = jax.jit(make_train_step(cfg, env1))(params, opt, batch)
-
-envN = make_env(cfg, mesh)
-p_sh = param_shardings(envN, axes, jax.eval_shape(lambda: params))
-params_s = jax.tree.map(lambda x, s: jax.device_put(x, s), params, p_sh)
-opt_s = adamw.init(params_s)
-batch_s = {"tokens": jax.device_put(batch["tokens"],
-                                    NamedSharding(mesh, P("data", None)))}
-lossN, pN, _ = jax.jit(make_train_step(cfg, envN))(params_s, opt_s, batch_s)
-assert abs(float(loss1) - float(lossN)) < 2e-3, (float(loss1), float(lossN))
-d = max(float(jnp.abs(a - np.asarray(b)).max())
-        for a, b in zip(jax.tree.leaves(p1), jax.tree.leaves(pN)))
-assert d < 2e-3, d
-print("train_step sharded==unsharded OK", float(loss1), float(lossN))
-
-# ------------- flash-decoding == plain decode (kv% tp != 0) ------------- #
-cfg = fp32(get_config("llama3-8b", smoke=True))   # kv=2, tp=4 -> flash path
-dec = decode_module(cfg)
-mod = model_module(cfg)
-params, axes = mod.init(jax.random.PRNGKey(2), cfg)
-b, s, m = 2, 16, 32
-batch = {"tokens": jax.random.randint(jax.random.PRNGKey(3), (b, s), 0,
-                                      cfg.vocab)}
-env1 = make_env(cfg, None)
-lg1, cache1 = dec.prefill(params, batch, cfg, env1, m)
-tok = jnp.argmax(lg1, -1)[:, None].astype(jnp.int32)
-lg1b, _ = dec.decode_step(params, cache1, tok, jnp.int32(s), cfg, env1)
-
-envN = make_env(cfg, mesh)
-assert envN.flash_decode, "kv=2 % tp=4 != 0 must enable flash decode"
-lgN, cacheN = jax.jit(lambda p, bt: dec.prefill(p, bt, cfg, envN, m))(params, batch)
-c_sh = {k: NamedSharding(mesh, envN.spec_sized(ax, cacheN[k].shape))
-        for k, ax in dec.cache_spec(cfg, b, m, envN)[1].items()}
-cacheN = jax.tree.map(lambda x, s: jax.device_put(x, s), cacheN, c_sh)
-lgNb, _ = jax.jit(lambda p, c, t, i: dec.decode_step(p, c, t, i, cfg, envN))(
-    params, cacheN, tok, jnp.int32(s))
-dd = float(jnp.abs(lg1b - np.asarray(lgNb)).max())
-assert dd < 2e-3, dd
-print("flash_decode == plain decode OK", dd)
-
-# ------------------- MoE shard_map EP == local dispatch ------------------ #
-from repro.models import moe as moe_mod
-cfg = fp32(get_config("deepseek-moe-16b", smoke=True))
-p, _ = moe_mod.moe_init(jax.random.PRNGKey(4), cfg)
-x = jax.random.normal(jax.random.PRNGKey(5), (4, 16, cfg.d_model))
-out1, aux1 = moe_mod.moe_apply(p, x, cfg, make_env(cfg, None))
-outN, auxN = jax.jit(lambda p, x: moe_mod.moe_apply(p, x, cfg,
-                                                    make_env(cfg, mesh)))(p, x)
-# EP partitions the capacity per (data-shard, expert): with tokens split
-# across 2 data shards the dropping boundary can differ for a few tokens;
-# compare the overwhelming majority instead of a strict allclose
-diff = jnp.abs(out1 - np.asarray(outN)).max(axis=-1).ravel()
-frac_equal = float((diff < 2e-3).mean())
-assert frac_equal > 0.95, frac_equal
-assert abs(float(aux1) - float(auxN)) < 1e-3
-print("moe shard_map ~= local OK", frac_equal)
+check_sharded_equivalence(make_mesh((2, 4), ("data", "model")))
 print("ALL-OK")
 """
 
@@ -122,6 +47,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from repro.configs import get_config
 from repro.configs.shapes import ShapeSpec
+from repro.launch.mesh import make_mesh
 from repro.parallel.sharding import make_env
 from repro.runtime.train_loop import TrainConfig, train
 import tempfile, dataclasses
@@ -138,13 +64,13 @@ ref = train(cfg, shape, env0, TrainConfig(steps=6, log_every=100),
 
 with tempfile.TemporaryDirectory() as d:
     # 3 steps on a (2,4) mesh, checkpoint...
-    mesh_a = jax.make_mesh((2, 4), ("data", "model"))
+    mesh_a = make_mesh((2, 4), ("data", "model"))
     env_a = make_env(cfg, mesh_a)
     train(cfg, shape, env_a, TrainConfig(steps=3, checkpoint_every=3,
                                          checkpoint_dir=d, log_every=100),
           verbose=False)
     # ...then ELASTIC RESCALE: resume on a (4,2) mesh (pod loss scenario)
-    mesh_b = jax.make_mesh((4, 2), ("data", "model"))
+    mesh_b = make_mesh((4, 2), ("data", "model"))
     env_b = make_env(cfg, mesh_b)
     out = train(cfg, shape, env_b, TrainConfig(steps=6, checkpoint_every=100,
                                                checkpoint_dir=d,

@@ -129,6 +129,30 @@ def test_retry_step_recovers():
         retry_step(lambda: (_ for _ in ()).throw(RuntimeError("x")), retries=2)
 
 
+def test_retry_step_raises_first_error_of_donating_step():
+    """A failed attempt of a step that donates its inputs deletes them; the
+    caller must see the attempt's own error, not the deleted-buffer errors
+    of the retries."""
+    import jax
+    import jax.numpy as jnp
+
+    donating = jax.jit(lambda x: x * 2, donate_argnums=(0,))
+    calls = []
+
+    def step(x):
+        calls.append(x)
+        donating(x)
+        raise FloatingPointError("loss is nan")
+
+    errors = []
+    x = jnp.ones(4)
+    with pytest.raises(FloatingPointError, match="loss is nan"):
+        retry_step(step, x, retries=3, on_error=lambda i, e: errors.append(e))
+    assert x.is_deleted()
+    assert len(calls) == 3
+    assert not any(isinstance(e, FloatingPointError) for e in errors[1:])
+
+
 def test_elastic_remesh_single_device():
     mesh, dropped = elastic_remesh()
     assert mesh.shape["model"] >= 1 and mesh.shape["data"] >= 1

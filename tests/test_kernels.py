@@ -1,5 +1,5 @@
-"""Per-kernel shape/dtype sweeps against the pure-jnp oracles
-(interpret=True executes the kernel bodies on CPU)."""
+"""Per-kernel shape/dtype sweeps against the pure-jnp oracles (on the CPU
+the kernels run in interpret mode)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
