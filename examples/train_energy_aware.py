@@ -48,7 +48,7 @@ cfg = get_config("llama3-8b", smoke=True)
 shape = ShapeSpec("train", 64, 8, "train")
 env = make_env(cfg, None)
 metrics = train(cfg, shape, env,
-                TrainConfig(steps=args.steps, lr=1e-3, warmup=20,
+                TrainConfig(steps=args.steps, lr=1e-3,
                             log_every=25,
                             checkpoint_dir=results_dir("ckpt_energy_aware"),
                             checkpoint_every=100),

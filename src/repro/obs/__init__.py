@@ -19,6 +19,13 @@ The package has two faces:
 ``suppressed()`` masks recording on the current thread; the cluster node
 uses it while uploading its own span file through the (instrumented)
 store client, which would otherwise trace its own flushes forever.
+
+Installing a recorder made with ``annotate=True`` also starts, once per
+process, a listener on JAX's compile timings: each trace of a jitted
+function to a jaxpr becomes an instant event ``jit.trace`` and each XLA
+compilation (or load from the persistent cache) ``jit.compile``, with the
+``seconds`` it took and the function's name ``fun``, on whatever recorder
+is current when it happens.
 """
 from __future__ import annotations
 
@@ -47,6 +54,12 @@ __all__ = [
 
 _default: SpanRecorder | None = None
 _tls = threading.local()
+_compile_lock = threading.Lock()
+_hearing_compiles = False
+
+# JAX's compile timings (``jax.monitoring`` duration events) -> event name
+_COMPILE_EVENTS = {"/jax/core/compile/jaxpr_trace_duration": "jit.trace",
+                  "/jax/core/compile/backend_compile_duration": "jit.compile"}
 
 
 class _Noop:
@@ -65,10 +78,30 @@ class _Noop:
 _NOOP = _Noop()
 
 
+def _on_compile(event: str, seconds: float, **kw) -> None:
+    name = _COMPILE_EVENTS.get(event)
+    if name is None:
+        return
+    rec = current()
+    if rec is not None:
+        rec.event(name, "jit", seconds=seconds, fun=kw.get("fun_name"))
+
+
+def _hear_compiles() -> None:
+    global _hearing_compiles
+    with _compile_lock:
+        if not _hearing_compiles:
+            import jax.monitoring
+            jax.monitoring.register_event_duration_secs_listener(_on_compile)
+            _hearing_compiles = True
+
+
 def install(rec: SpanRecorder, *, thread_only: bool = False) -> SpanRecorder:
     """Make ``rec`` the ambient recorder — process-wide, or for this
     thread only (shadowing the process default)."""
     global _default
+    if rec.annotate:
+        _hear_compiles()
     if thread_only:
         _tls.rec = rec
     else:

@@ -22,6 +22,11 @@ Tests inject a deterministic ``clock`` callable instead.
 
 Recording is allocation-light but not free; the ambient helpers in
 ``repro.obs`` are the zero-cost path when profiling is off.
+
+``annotate=True`` also writes every span and event into ``jax.profiler``'s
+host trace, as a ``TraceAnnotation`` of the same name, so a profile of the
+device names what the host was doing on the device's own clock.  Only that
+path imports JAX.
 """
 from __future__ import annotations
 
@@ -87,19 +92,25 @@ class _LiveSpan:
 
 class _SpanCtx:
     """Lexical ``with`` wrapper around begin/end that maintains the
-    per-thread ambient parent stack."""
+    per-thread ambient parent stack (and, on an annotating recorder, holds
+    the span's profiler annotation open)."""
 
-    __slots__ = ("_rec", "_live")
+    __slots__ = ("_rec", "_live", "_ann")
 
     def __init__(self, rec, live):
         self._rec = rec
         self._live = live
+        self._ann = None if rec._annotation is None else rec._annotation(live.name)
 
     def __enter__(self) -> _LiveSpan:
         self._rec._stack().append(self._live.sid)
+        if self._ann is not None:
+            self._ann.__enter__()
         return self._live
 
     def __exit__(self, exc_type, exc, tb) -> bool:
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
         stack = self._rec._stack()
         if stack and stack[-1] == self._live.sid:
             stack.pop()
@@ -119,9 +130,14 @@ class SpanRecorder:
     """
 
     def __init__(self, actor: str, path: str | None = None, *,
-                 clock=None, flush_every: int = _CHUNK):
+                 clock=None, flush_every: int = _CHUNK, annotate: bool = False):
         self.actor = str(actor)
         self.path = path
+        self.annotate = bool(annotate)
+        self._annotation = None
+        if self.annotate:
+            from jax.profiler import TraceAnnotation
+            self._annotation = TraceAnnotation
         if clock is None:
             epoch = time.time() - time.perf_counter()
             clock = lambda: epoch + time.perf_counter()  # noqa: E731
@@ -199,6 +215,9 @@ class SpanRecorder:
 
     def event(self, name: str, cat: str, parent=_AMBIENT, **attrs) -> str:
         """Instant event (zero-duration point on the timeline)."""
+        if self._annotation is not None:
+            with self._annotation(name):
+                pass
         sid = self._next_sid()
         t = self.now()
         row = {"sid": sid, "parent": self._resolve_parent(parent),

@@ -1,3 +1,3 @@
-from repro.optim import adamw, schedules
+from repro.optim import adamw
 
-__all__ = ["adamw", "schedules"]
+__all__ = ["adamw"]

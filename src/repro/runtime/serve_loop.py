@@ -9,6 +9,14 @@ it at the prefill->decode region boundary and again after decode.  Wrap
 ``device`` in :class:`repro.trace.TracedBackend` and every plan decision
 (with its reason) plus the issued frequency commands land in a replayable
 telemetry trace.
+
+With a :mod:`repro.obs` recorder installed, each call records a
+``serve.call`` span (attributes ``batch``, ``prompt_len``, ``new_tokens``)
+holding ``serve.prefill`` (dispatch and the wait on its logits), the
+``serve.first_token`` event, ``serve.plan`` around each governor call, one
+``serve.step`` per decode step (dispatch, eager argmax, append) and
+``serve.wait`` (the wait on the last token).  With none installed each of
+these costs one thread-local read.
 """
 from __future__ import annotations
 
@@ -19,6 +27,7 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.configs.registry import decode_module
 
 
@@ -49,37 +58,45 @@ def serve(cfg, env, params, batch, sc: ServeConfig | None = None,
     b, s = batch["tokens"].shape
     max_len = max_len or (s + sc.max_new_tokens)
 
-    t0 = time.perf_counter()
-    logits, cache = _prefill(params, batch, cfg, env, max_len)
-    jax.block_until_ready(logits)
-    t_prefill = time.perf_counter() - t0
+    with obs.span("serve.call", "serve", batch=b, prompt_len=s,
+                  new_tokens=sc.max_new_tokens):
+        t0 = time.perf_counter()
+        with obs.span("serve.prefill", "serve"):
+            logits, cache = _prefill(params, batch, cfg, env, max_len)
+            jax.block_until_ready(logits)
+        t_prefill = time.perf_counter() - t0
+        obs.event("serve.first_token", "serve")
 
-    if governor is not None:
-        from repro.dvfs.planner import Region
-        # decode is memory-bound; one step costs roughly a prefill over a
-        # single token, so the burst lasts ~(t_prefill / prompt_len) per
-        # generated token
-        per_step = max(t_prefill / max(s, 1), 1e-5)
-        governor.plan(Region("memory", per_step * sc.max_new_tokens),
-                      device)
+        if governor is not None:
+            from repro.dvfs.planner import Region
+            # decode is memory-bound; one step costs roughly a prefill over a
+            # single token, so the burst lasts ~(t_prefill / prompt_len) per
+            # generated token
+            per_step = max(t_prefill / max(s, 1), 1e-5)
+            with obs.span("serve.plan", "serve"):
+                governor.plan(Region("memory", per_step * sc.max_new_tokens),
+                              device)
 
-    tok = jnp.argmax(logits, -1)[:, None].astype(jnp.int32)
-    out = [tok]
-    t0 = time.perf_counter()
-    for i in range(sc.max_new_tokens - 1):
-        logits, cache = _decode_step(params, cache, tok, jnp.int32(s + i),
-                                     cfg, env)
         tok = jnp.argmax(logits, -1)[:, None].astype(jnp.int32)
-        out.append(tok)
-    jax.block_until_ready(tok)
-    t_decode = time.perf_counter() - t0
+        out = [tok]
+        t0 = time.perf_counter()
+        for i in range(sc.max_new_tokens - 1):
+            with obs.span("serve.step", "serve"):
+                logits, cache = _decode_step(params, cache, tok,
+                                             jnp.int32(s + i), cfg, env)
+                tok = jnp.argmax(logits, -1)[:, None].astype(jnp.int32)
+                out.append(tok)
+        with obs.span("serve.wait", "serve"):
+            jax.block_until_ready(tok)
+        t_decode = time.perf_counter() - t0
 
-    if governor is not None:
-        from repro.dvfs.planner import Region
-        # next prefill burst is compute-bound: plan back up
-        governor.plan(Region("compute", max(t_prefill, 1e-3)), device)
+        if governor is not None:
+            from repro.dvfs.planner import Region
+            # next prefill burst is compute-bound: plan back up
+            with obs.span("serve.plan", "serve"):
+                governor.plan(Region("compute", max(t_prefill, 1e-3)), device)
 
-    tokens = jnp.concatenate(out, axis=1)
+        tokens = jnp.concatenate(out, axis=1)
     return {
         "tokens": tokens,
         "logits": logits,              # of the last generated token

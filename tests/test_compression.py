@@ -83,6 +83,6 @@ def test_train_loop_with_compression():
     cfg = get_config("llama3-8b", smoke=True)
     shape = ShapeSpec("t", 32, 4, "train")
     m = train(cfg, shape, make_env(cfg, None),
-              TrainConfig(steps=20, lr=2e-3, warmup=5, log_every=100,
+              TrainConfig(steps=20, lr=2e-3, log_every=100,
                           grad_compression=True), verbose=False)
     assert np.mean(m["loss"][-3:]) < np.mean(m["loss"][:3])

@@ -130,6 +130,28 @@ def test_ambient_api_is_noop_when_off():
     assert obs.span("z", "w") is cm              # shared no-op, no alloc
 
 
+def test_plain_recorder_imports_no_jax():
+    """Campaign workers record spans without JAX: only ``annotate=True``
+    imports it."""
+    import subprocess
+    import sys
+
+    code = ("import sys\n"
+            "from repro import obs\n"
+            "rec = obs.install(obs.SpanRecorder('worker'))\n"
+            "with obs.span('unit.exec', 'exec'):\n"
+            "    obs.event('sched.requeue', 'sched')\n"
+            "assert len(rec.rows()) == 2\n"
+            "assert 'jax' not in sys.modules, 'jax imported'\n")
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.pathsep.join(
+        [os.path.join(os.path.dirname(__file__), os.pardir, "src"),
+         env.get("PYTHONPATH", "")])
+    p = subprocess.run([sys.executable, "-c", code], env=env,
+                       capture_output=True, text=True, timeout=120)
+    assert p.returncode == 0, p.stderr
+
+
 def test_thread_local_recorder_shadows_process_default_and_suppressed():
     proc = obs.install(SpanRecorder("proc", clock=_fake_clock()))
     local = SpanRecorder("node", clock=_fake_clock())

@@ -17,7 +17,7 @@ ENV = make_env(None, None)
 def test_train_loss_decreases():
     cfg = get_config("llama3-8b", smoke=True)
     shape = ShapeSpec("t", 32, 4, "train")
-    m = train(cfg, shape, ENV, TrainConfig(steps=60, lr=2e-3, warmup=10,
+    m = train(cfg, shape, ENV, TrainConfig(steps=60, lr=2e-3,
                                            log_every=100), verbose=False)
     first = np.mean(m["loss"][:5])
     last = np.mean(m["loss"][-5:])
