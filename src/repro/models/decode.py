@@ -15,6 +15,9 @@ Cache layouts (M = max_len, L = n_layers):
 
 ``pos`` is the number of *text* tokens already consumed; the new token sits
 at text index ``pos`` (hybrid adds the n_meta offset internally).
+
+The ssm and hybrid decode steps update their layer-stacked caches where they
+lie (``_ssm_layers``), so a donated cache is never copied whole.
 """
 from __future__ import annotations
 
@@ -308,6 +311,40 @@ def _hybrid_prefill(params, x, cfg, env, s, max_len):
 # --------------------------------------------------------------------------- #
 # decode
 # --------------------------------------------------------------------------- #
+def _ssm_layers(body, x, xs, cache, pre=""):
+    """Loop a decode step over a stack of layers whose SSM states, the
+    caches ``{pre}conv_x``, ``{pre}conv_B``, ``{pre}conv_C`` and ``{pre}h``,
+    are updated in place.
+
+    ``body(x, xs_l, state)`` gets a layer's slice of ``xs`` (its weights and
+    read-only caches) and its state, and returns (x, new state, out); the
+    outs are stacked over the layers.  The state stacks ride in the loop's
+    carry and each layer writes back only its own slice.  Collected as scan
+    outputs instead, the new states would land in a fresh buffer that XLA
+    then copies whole into the donated cache, every token.
+    """
+    names = [f"{pre}conv_{k}" for k in "xBC"] + [f"{pre}h"]
+
+    def step(carry, xs_l):
+        x, stacks, i = carry
+        conv = {k: jax.lax.dynamic_index_in_dim(stacks[f"{pre}conv_{k}"], i,
+                                                keepdims=False)
+                for k in "xBC"}
+        # the SSM state keeps its layer axis, (1,B,H,P,N): squeezed, the
+        # bitcasts around its update keep XLA:TPU from fusing the update
+        # into the in-place write, and it copies the layer's state out first
+        hs = jax.lax.dynamic_slice_in_dim(stacks[f"{pre}h"], i, 1)
+        x, (conv, hs), out = body(x, xs_l, (conv, hs))
+        new = [conv[k] for k in "xBC"] + [hs]
+        stacks = {n: jax.lax.dynamic_update_index_in_dim(stacks[n], v, i, 0)
+                  for n, v in zip(names, new)}
+        return (x, stacks, i + 1), out
+
+    stacks = {n: cache[n] for n in names}
+    (x, stacks, _), outs = jax.lax.scan(step, (x, stacks, jnp.int32(0)), xs)
+    return x, {**cache, **stacks}, outs
+
+
 def decode_step(params, cache, token, pos, cfg, env):
     """token: (B,1) int32; pos: () int32.  Returns (logits (B,V), cache)."""
     fam = cfg.family
@@ -342,17 +379,11 @@ def decode_step(params, cache, token, pos, cfg, env):
             body, x, (params["blocks"], cache["c_lat"], cache["k_rope"]))
         cache["c_lat"], cache["k_rope"] = cs, rs
     elif fam == "ssm":
-        def body(h, inp):
-            p, cx, cb, cc, hs = inp
+        def body(h, p, state):
             hh = layers.rms_norm(h, p["ln"])
-            y, (conv, hs) = ssm_mod.ssm_decode(
-                p["mix"], hh, ({"x": cx, "B": cb, "C": cc}, hs), cfg, env)
-            return h + y, (conv["x"], conv["B"], conv["C"], hs)
-        x, (cx, cb, cc, hs) = jax.lax.scan(
-            body, x, (params["blocks"], cache["conv_x"], cache["conv_B"],
-                      cache["conv_C"], cache["h"]))
-        cache["conv_x"], cache["conv_B"], cache["conv_C"], cache["h"] = \
-            cx, cb, cc, hs
+            y, state = ssm_mod.ssm_decode(p["mix"], hh, state, cfg, env)
+            return h + y, state, None
+        x, cache, _ = _ssm_layers(body, x, params["blocks"], cache)
     elif fam == "hybrid":
         x, cache = _hybrid_decode(params, cache, x, pos, cfg, env)
     else:
@@ -384,6 +415,8 @@ def _hybrid_global_decode(p, x, kc, vc, conv, hs, pos, cfg, env):
 
 
 def _hybrid_window_decode(p, x, rk, rv, mk, mv, conv, hs, pos, cfg, env):
+    """Returns (x, k, v, conv, hs): the new token's K/V for the ring's slot,
+    which the caller writes."""
     hy = cfg.hybrid
     b = x.shape[0]
     w = hy.window
@@ -408,7 +441,7 @@ def _hybrid_window_decode(p, x, rk, rv, mk, mv, conv, hs, pos, cfg, env):
                 + bta[1] * layers.rms_norm(sso, p["ns"]))).astype(cfg.compute_dtype)
     x = x + y
     h2 = layers.rms_norm(x, p["ln2"])
-    return x + layers.mlp_apply(p["ffn"], h2, cfg), rk, rv, conv, hs
+    return x + layers.mlp_apply(p["ffn"], h2, cfg), k, v, conv, hs
 
 
 def _hybrid_decode(params, cache, x, pos, cfg, env):
@@ -430,20 +463,23 @@ def _hybrid_decode(params, cache, x, pos, cfg, env):
         return x
 
     def seg(x, name, pstack):
-        def body(h, inp):
-            p, rk, rv, mk, mv, cx, cb, cc, hs = inp
-            conv = {"x": cx, "B": cb, "C": cc}
-            h, rk, rv, conv, hs = _hybrid_window_decode(
-                p, h, rk, rv, mk, mv, conv, hs, pos, cfg, env)
-            return h, (rk, rv, conv["x"], conv["B"], conv["C"], hs)
-        x, (rk, rv, cx, cb, cc, hs) = jax.lax.scan(
+        def body(h, inp, state):
+            p, rk, rv, mk, mv = inp
+            h, k, v, conv, hs = _hybrid_window_decode(
+                p, h, rk, rv, mk, mv, *state, pos, cfg, env)
+            return h, (conv, hs), (k, v)
+        x, new, (ks, vs) = _ssm_layers(
             body, x, (pstack, cache[f"{name}_k"], cache[f"{name}_v"],
-                      cache[f"{name}_mk"], cache[f"{name}_mv"],
-                      cache[f"{name}_conv_x"], cache[f"{name}_conv_B"],
-                      cache[f"{name}_conv_C"], cache[f"{name}_h"]))
-        cache[f"{name}_k"], cache[f"{name}_v"] = rk, rv
-        cache[f"{name}_conv_x"], cache[f"{name}_conv_B"] = cx, cb
-        cache[f"{name}_conv_C"], cache[f"{name}_h"] = cc, hs
+                      cache[f"{name}_mk"], cache[f"{name}_mv"]),
+            cache, f"{name}_")
+        # the rings are read-only in the loop: carried, XLA:TPU would lay
+        # them out for the attention inside it and copy them in and out.
+        # Every layer's new slot is written in place after it.
+        slot = jnp.mod(pos, cfg.hybrid.window)
+        for nm, kv in (("k", ks), ("v", vs)):
+            new[f"{name}_{nm}"] = jax.lax.dynamic_update_slice(
+                new[f"{name}_{nm}"], kv, (0, 0, slot, 0, 0))
+        cache.update(new)
         return x
 
     x = g(x)

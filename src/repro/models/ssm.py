@@ -191,7 +191,8 @@ def ssm_forward(p, x, cfg, env, conv_state=None, ssd_state=None):
 
 
 def ssm_decode(p, x, state, cfg, env):
-    """Single-token recurrent step.  x: (B,1,D); state=(conv_state, h)."""
+    """Single-token recurrent step.  x: (B,1,D); state=(conv_state, h), h
+    (B,H,P,N) or a layer's slice (1,B,H,P,N) of a stacked cache."""
     s = cfg.ssm
     cd = cfg.compute_dtype
     di = s.d_inner
@@ -203,7 +204,8 @@ def ssm_decode(p, x, state, cfg, env):
     dA = jnp.exp(dt[:, 0, :] * A[None])                          # (B,h)
     dBx = jnp.einsum("bh,bn,bhp->bhpn", dt[:, 0], B[:, 0].astype(jnp.float32), xh)
     hnew = hstate * dA[..., None, None] + dBx
-    y = jnp.einsum("bn,bhpn->bhp", C[:, 0].astype(jnp.float32), hnew)
+    y = jnp.einsum("bn,bhpn->bhp", C[:, 0].astype(jnp.float32),
+                   hnew.reshape(hnew.shape[-4:]))
     y = y + xh * p["D"][None, :, None]
     y = y.reshape(xs.shape[0], 1, di).astype(cd)
     y = y * jax.nn.silu(z)

@@ -1,18 +1,22 @@
-"""The Pallas kernels compile for a TPU v5e at the widths the main path uses.
+"""The Pallas kernels compile for a TPU v5e at the widths the main path uses,
+and the serving decode step compiles there without copying its caches.
 
-Nothing runs: each test compiles one kernel for a chip described by
-``jax.experimental.topologies`` (the TPU compiler is installed with JAX),
-which refuses what interpret mode accepts — misaligned blocks, too much
-VMEM.  The topology is described inside a module-scoped fixture, never at
+Nothing runs: each test compiles one kernel, or one decode step, for a chip
+described by ``jax.experimental.topologies`` (the TPU compiler is installed
+with JAX), which refuses what interpret mode accepts — misaligned blocks,
+too much VMEM — and shows the copies XLA:TPU inserts, which XLA:CPU does not
+predict.  The topology is described inside a module-scoped fixture, never at
 import, so every pytest-xdist worker collects the same tests and only the
 worker that runs this file loads the TPU library.  Keep all such compiles
 in this one file.
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -20,6 +24,9 @@ from repro.configs import get_config
 from repro.kernels.flash_attention.kernel import flash_attention_kernel
 from repro.kernels.microbench.kernel import TILE, microbench_kernel
 from repro.kernels.ssd.kernel import ssd_chunk_kernel
+from repro.models import decode, lm
+from repro.parallel.sharding import make_env
+from repro.runtime import serve_loop
 
 
 @pytest.fixture(scope="module")
@@ -82,3 +89,68 @@ def test_ssd_compiles_for_v5e_at_mamba2_widths(one_chip):
                    ((b, nc, h, q), jnp.float32),
                    sharding=one_chip)
     assert "tpu_custom_call" in hlo
+
+
+# the serving benchmark's decode shapes: (arch, batch, prompt + new tokens)
+DECODE_CASES = [("mamba2-130m", 64, 512 + 256), ("hymba-1.5b", 16, 1024 + 256)]
+_HLO_TYPES = {"float32": "f32", "bfloat16": "bf16"}
+
+
+def _decode_step_hlo(arch, batch, max_len, sharding=None):
+    """``serve_loop._decode_step`` (cache donated) compiled at full width;
+    returns its HLO text and the cache's shapes and logical axes."""
+    cfg = get_config(arch)
+    params = jax.eval_shape(lambda k: lm.init(k, cfg)[0], jax.random.PRNGKey(0))
+    cache, axes = decode.cache_spec(cfg, batch, max_len)
+
+    def spec(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=sharding), tree)
+    tok = jax.ShapeDtypeStruct((batch, 1), jnp.int32, sharding=sharding)
+    pos = jax.ShapeDtypeStruct((), jnp.int32, sharding=sharding)
+    compiled = serve_loop._decode_step.lower(
+        spec(params), spec(cache), tok, pos, cfg, make_env(cfg, None)).compile()
+    return compiled.as_text(), cache, axes
+
+
+def _stack_copies(hlo, cache, axes):
+    """Names of the copies in compiled HLO text of a layer-stacked cache leaf
+    whole, anywhere, or of one layer's slice of one inside a loop.  (The
+    hybrid family's global layers keep unstacked conv states of a few
+    hundred KB, which the entry computation copies: their new state is the
+    old one shifted, written into the same donated buffer.)"""
+    stacks = [(_HLO_TYPES[np.dtype(a.dtype).name], tuple(a.shape))
+              for name, a in cache.items() if axes[name][0] is None]
+    whole = set(stacks)
+    layer = {(t, shape[1:]) for t, shape in stacks}
+    found, comp = [], ""
+    for line in hlo.splitlines():
+        if line.endswith("{") and not line.startswith(" "):
+            comp = line
+        m = re.match(r"\s*(?:ROOT )?%([\w.-]+) = \(?(\w+)\[([\d,]*)\]", line)
+        if m is None or not re.search(r"[)}] copy(-start)?\(", line):
+            continue
+        key = (m[2], tuple(int(d) for d in m[3].split(",") if d))
+        if key in whole or (key in layer and not comp.startswith("ENTRY")):
+            found.append(m[1])
+    return found
+
+
+@pytest.mark.parametrize("arch,batch,max_len", DECODE_CASES)
+def test_decode_step_updates_cache_stacks_in_place_for_v5e(
+        one_chip, arch, batch, max_len):
+    """No layer-stacked cache is copied whole, nor a layer of one inside the
+    layer loop: the donated cache is updated where it lies."""
+    hlo, cache, axes = _decode_step_hlo(arch, batch, max_len, one_chip)
+    assert any(axes[n][0] is None for n in cache)
+    assert _stack_copies(hlo, cache, axes) == []
+
+
+@pytest.mark.parametrize("arch,batch,max_len", DECODE_CASES)
+def test_decode_step_updates_cache_stacks_in_place_on_the_chip(
+        arch, batch, max_len):
+    """The same, compiled for the TPU this process holds."""
+    if jax.default_backend() != "tpu":
+        pytest.skip("needs a TPU")
+    hlo, cache, axes = _decode_step_hlo(arch, batch, max_len)
+    assert _stack_copies(hlo, cache, axes) == []
